@@ -19,12 +19,12 @@ from __future__ import annotations
 from typing import List
 
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.findings import Finding
 from repro.analysis.jaxpr_utils import iter_eqns, sub_jaxprs_of, var_consumers, var_producers
 
-_HOST_SYNC = {"infeed", "outfeed"}
+_HOST_SYNC = {"infeed", "outfeed", "debug_print"}
 # consumers for which a widening convert is an accumulator idiom, not a bug
 _ACCUMULATOR_CONSUMERS = {"dot_general", "conv_general_dilated", "reduce_sum",
                           "reduce_max", "reduce_min", "reduce_prod"}

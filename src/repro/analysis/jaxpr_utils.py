@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from typing import Any, Iterator, List, Tuple
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def _as_jaxpr(obj) -> Any:
@@ -45,13 +45,11 @@ def iter_eqns(closed_jaxpr) -> Iterator[Tuple[Any, Any]]:
 
 
 def var_producers(jaxpr) -> dict:
-    """Map each Var to the eqn that produces it (within one jaxpr)."""
-    prod = {}
-    for eqn in jaxpr.eqns:
-        for v in eqn.outvars:
-            if not isinstance(v, jcore.DropVar):
-                prod[v] = eqn
-    return prod
+    """Map each Var to the eqn that produces it (within one jaxpr).
+
+    Dropped outputs are ``Var`` subclasses that no equation reads, so
+    their entries are never looked up and need no filtering."""
+    return {v: eqn for eqn in jaxpr.eqns for v in eqn.outvars}
 
 
 def var_consumers(jaxpr) -> dict:

@@ -24,7 +24,7 @@ from typing import Any, Dict, FrozenSet, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.findings import Finding
 from repro.analysis.jaxpr_utils import _as_jaxpr
@@ -59,8 +59,7 @@ def _taint_jaxpr(
         return env.get(atom, _EMPTY)
 
     def write(var, taint: Taint) -> None:
-        if not isinstance(var, jcore.DropVar):
-            env[var] = taint
+        env[var] = taint  # a dropped output is written but never read
 
     if len(jaxpr.invars) != len(in_taints):
         raise ValueError(

@@ -4,9 +4,9 @@ TPU has no sparse tensor cores, so the honest N:M win on TPU is **HBM
 bandwidth and footprint** (DESIGN.md §3): a 2:4 weight stores N/M = ½ the
 values plus int8 group offsets (2-bit packable), i.e. ~0.56× the bytes of
 the dense bf16 weight. This kernel streams the *compressed* representation
-HBM→VMEM, decompresses each (bk, bn) weight tile in VMEM with a
-compare-and-accumulate (no scatter — TPU-vector friendly), and feeds the
-dense tile straight to the MXU.
+HBM→VMEM, decompresses each (bk, bn) weight tile in VMEM with compares
+and 0/1 expansion matmuls (no scatter or gather — TPU-vector friendly),
+and feeds the dense tile straight to the MXU.
 
 Layout (produced by sparsity/sparse_params.nm_compress):
     vals (K//m·n, N)   kept values, group-major along K
@@ -35,19 +35,23 @@ def _kernel(x_ref, v_ref, i_ref, o_ref, acc_ref, *, n: int, m: int, k_steps: int
 
     vals = v_ref[...]                      # (G*n, bn)
     idx = i_ref[...].astype(jnp.int32)     # (G*n, bn)
-    G = vals.shape[0] // n
-    bn = vals.shape[1]
+    bkc = vals.shape[0]
+    bk = bkc // n * m
 
-    # VMEM decompress: dense[g, o, c] = Σ_s vals[g, s, c] · [idx[g, s, c] == o]
-    vals_g = vals.reshape(G, n, bn)
-    idx_g = idx.reshape(G, n, bn)
-    dense = jnp.zeros((G, m, bn), vals.dtype)
-    for s in range(n):  # n is tiny (1..4): unrolled compare-accumulate
-        onehot = (
-            idx_g[:, s, None, :] == jax.lax.broadcasted_iota(jnp.int32, (G, m, bn), 1)
-        )
-        dense = dense + jnp.where(onehot, vals_g[:, s, None, :], 0)
-    w_tile = dense.reshape(G * m, bn)      # (bk, bn)
+    # VMEM decompress with 2-D ops only (Mosaic lowers no 3-D gather):
+    # dense row g*m+o = sum over the group's n kept rows whose offset is o,
+    # i.e. dense = sum_o Q_o @ where(idx == o, vals, 0) with the 0/1
+    # expansion Q_o[g*m+o, g*n+s] = 1. HIGHEST keeps the f32 values exact.
+    row = jax.lax.broadcasted_iota(jnp.int32, (bk, bkc), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bk, bkc), 1)
+    same_group = (row // m) == (col // n)
+    w_tile = jnp.zeros((bk, vals.shape[1]), jnp.float32)
+    for o in range(m):  # m is tiny (2..8): unrolled
+        q = (same_group & (row % m == o)).astype(vals.dtype)
+        z = jnp.where(idx == o, vals, jnp.zeros_like(vals))
+        w_tile = w_tile + jnp.dot(q, z, preferred_element_type=jnp.float32,
+                                  precision=jax.lax.Precision.HIGHEST)
+    w_tile = w_tile.astype(vals.dtype)     # (bk, bn)
 
     acc_ref[...] += jnp.dot(x_ref[...], w_tile, preferred_element_type=jnp.float32)
 

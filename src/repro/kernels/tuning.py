@@ -162,10 +162,7 @@ def _backend_tag(interpret: bool) -> str:
 def _device_kind() -> str:
     import jax
 
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def _fmt(d: Dict[str, Any]) -> str:

@@ -136,7 +136,9 @@ def run_cell(
 
 def main(argv=None) -> None:
     from repro.launch.api import RunSpec
+    from repro.launch.compile_cache import use_compile_cache
 
+    use_compile_cache()
     spec = RunSpec.from_argv("dryrun", argv)
     archs = list_configs() if spec.arch == "all" else spec.arch.split(",")
     meshes = ["single", "multi"] if spec.mesh == "both" else [spec.mesh]

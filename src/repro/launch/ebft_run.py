@@ -31,11 +31,13 @@ identical either way (it is just a sink).
 from __future__ import annotations
 
 import time
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core import ebft, lora, mask_tuning
 from repro.core.evaluate import perplexity
 from repro.core.masks import prune
@@ -44,6 +46,7 @@ from repro.data.tokens import (
 )
 from repro.kernels import tuning
 from repro.launch.api import RunSpec
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_ebft_plan
 from repro.models.model import build
 from repro.obs import metrics as OM
@@ -93,7 +96,12 @@ def pretrain(model, params, corpus, steps: int, batch: int, seq: int, lr: float,
     return params
 
 
-def main(argv=None) -> None:
+def main(argv=None, cfg: Optional[ModelConfig] = None) -> Dict[str, Any]:
+    """Runs the pipeline; ``cfg`` (default ``get_config(--arch)``) lets a
+    caller hand in a cut configuration. Returns the model, the tuned
+    params, the masks, the per-block reports, the perplexities, the phase
+    times and the run artifact (None under ``--no-obs``)."""
+    use_compile_cache()
     spec = RunSpec.from_argv("ebft", argv)
     run = spec.start_obs_run()
     say = run.say if run is not None else print
@@ -107,7 +115,7 @@ def main(argv=None) -> None:
         say(f"calibration mesh: {plan.describe()['axes']} "
             f"({plan.device_count} devices)")
 
-    cfg = get_config(spec.arch)
+    cfg = cfg or get_config(spec.arch)
     model = build(cfg)
     corpus = SyntheticCorpus(CorpusConfig(vocab_size=cfg.vocab_size, seed=spec.seed))
     params = model.init(jax.random.PRNGKey(spec.seed))
@@ -201,6 +209,7 @@ def main(argv=None) -> None:
         phases["baseline_lora"] = sp.duration
         say(f"LoRA ppl           {ppl['LoRA']:8.2f}   ({sp.duration:.0f}s)")
 
+    payload = None
     if run is not None:
         summ = OM.summary()
         peak = summ.get("ebft/live_block_bytes", {}).get("max")
@@ -210,7 +219,7 @@ def main(argv=None) -> None:
         sync_max = max((r.host_syncs for r in reports), default=0)
         fused_all = bool(reports) and all(r.path == "fused" for r in reports)
         path = spec.bench_out
-        run.finish(
+        payload = run.finish(
             extra={
                 "phases": phases,
                 "blocks": [r.asdict() for r in reports],
@@ -281,6 +290,9 @@ def main(argv=None) -> None:
             summary_path=path,
         )
         print(f"wrote {path}  (render with: python -m repro.obs report {path})")
+    return {"model": model, "tuned": tuned, "masks": masks,
+            "reports": reports, "perplexity": ppl, "phases": phases,
+            "payload": payload}
 
 
 if __name__ == "__main__":

@@ -13,17 +13,25 @@ trillion-param configs.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
+
+
+def _auto(n: int):
+    # every axis Auto: GSPMD propagates shardings and the model code's
+    # plain gathers/constraints stay legal (make_mesh defaults to Explicit)
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh for CPU tests (requires <= available devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small (data, model) mesh over the first ``data * model`` devices."""
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def make_ebft_plan(data: int = 0, model: int = 1):
@@ -57,18 +65,9 @@ def make_ebft_plan(data: int = 0, model: int = 1):
 
 
 def make_abstract_mesh(shape, axis_names):
-    """Device-free mesh for sharding-rule checks (tests, repro.analysis).
-
-    The ``AbstractMesh`` constructor changed across jax releases:
-    newer versions take ``(axis_sizes, axis_names)``, 0.4.x takes a single
-    ``((name, size), ...)`` tuple. Try the new form first.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
+    """Device-free mesh for sharding-rule checks (tests, repro.analysis)."""
+    return AbstractMesh(tuple(shape), tuple(axis_names),
+                        axis_types=_auto(len(axis_names)))
 
 
 def abstract_production_mesh(*, multi_pod: bool = False):

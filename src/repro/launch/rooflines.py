@@ -1,10 +1,9 @@
 """Roofline terms from the dry-run's compiled artifact.
 
-Hardware model (TPU v5e-class chip, assignment constants):
-
-    peak bf16 compute   197 TFLOP/s / chip
-    HBM bandwidth       819 GB/s / chip
-    ICI link bandwidth  ~50 GB/s / link
+Hardware model: :data:`PEAKS`, keyed by ``jax.Device.device_kind``. The
+dry-run models the TPU v5e target (:data:`TARGET_KIND`); measured code
+looks its own device up and books no roofline figure for a kind that is
+not in the table.
 
 Terms (seconds per step, PER CHIP — the analyzer works on the partitioned
 per-device program, so no extra division by chip count is needed):
@@ -25,9 +24,13 @@ from typing import Any, Dict
 
 from repro.configs.base import ModelConfig, ShapeConfig
 
-PEAK_FLOPS = 197e12   # bf16 per chip
-HBM_BW = 819e9        # bytes/s per chip
-ICI_BW = 50e9         # bytes/s per link
+# Published per-chip peaks. Source: Google Cloud documentation, "TPU v5e"
+# (197 TFLOP/s bf16, 819 GB/s HBM); ICI is the dry-run's ~50 GB/s per-link
+# planning figure, not a published peak.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+TARGET_KIND = "TPU v5 lite"
 
 
 def model_flops_per_chip(cfg: ModelConfig, shape: ShapeConfig, chips: int) -> float:
@@ -77,9 +80,10 @@ def terms(
     shape: ShapeConfig,
     chips: int,
 ) -> Roofline:
-    compute_s = stats.flops / PEAK_FLOPS
-    memory_s = stats.hbm_bytes / HBM_BW
-    collective_s = stats.collective_wire / ICI_BW
+    peak = PEAKS[TARGET_KIND]
+    compute_s = stats.flops / peak["flops"]
+    memory_s = stats.hbm_bytes / peak["hbm_bw"]
+    collective_s = stats.collective_wire / peak["ici_bw"]
     names = ("compute", "memory", "collective")
     vals = (compute_s, memory_s, collective_s)
     bottleneck = names[max(range(3), key=lambda i: vals[i])]
@@ -93,5 +97,5 @@ def terms(
         model_flops_per_chip=mf,
         hlo_flops_per_chip=stats.flops,
         useful_ratio=mf / max(stats.flops, 1e-30),
-        roofline_fraction=(mf / PEAK_FLOPS) / bound,
+        roofline_fraction=(mf / peak["flops"]) / bound,
     )

@@ -14,25 +14,33 @@ parses through the deprecation shim).
 from __future__ import annotations
 
 import time
+from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
 
 from repro.checkpoint import ckpt as CK
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core.masks import prune
 from repro.data.tokens import CorpusConfig, SyntheticCorpus, calibration_set
 from repro.launch.api import RunSpec
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build
 from repro.obs import metrics as OM
 from repro.serving.decode import Request, Server
 
 
-def main(argv=None) -> None:
+def main(argv=None, cfg: Optional[ModelConfig] = None) -> Dict[str, Any]:
+    """Serves the requests; ``cfg`` (default ``get_config(--arch)``) lets a
+    caller hand in a cut configuration. Returns the model, the served
+    params, the generated ids per request, the serving wall time and the
+    run artifact (None under ``--no-obs``)."""
+    use_compile_cache()
     spec = RunSpec.from_argv("serve", argv)
     run = spec.start_obs_run()
 
-    cfg = get_config(spec.arch)
+    cfg = cfg or get_config(spec.arch)
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(spec.seed))
     if spec.ckpt_dir:
@@ -64,13 +72,17 @@ def main(argv=None) -> None:
           f"{spec.slots} slots)")
     for uid in sorted(results)[:3]:
         print(f"  req {uid}: {results[uid][:8]}...")
+    payload = None
     if run is not None:
         occ = OM.summary().get("serve/batch_occupancy", {})
         print(f"  mean batch occupancy "
               f"{(occ.get('mean') or 0.0) * 100:.0f}% over {spec.slots} slots")
-        run.finish(extra={"served": {"requests": len(results), "tokens": toks,
-                                     "tokens_per_s": toks / max(dt, 1e-9)}},
-                   summary_path=spec.bench_out or None)
+        payload = run.finish(
+            extra={"served": {"requests": len(results), "tokens": toks,
+                              "tokens_per_s": toks / max(dt, 1e-9)}},
+            summary_path=spec.bench_out or None)
+    return {"model": model, "params": params, "results": results,
+            "seconds": dt, "payload": payload}
 
 
 if __name__ == "__main__":

@@ -32,6 +32,7 @@ from repro.data.tokens import CorpusConfig, SyntheticCorpus
 from repro.distributed import sharding as SH
 from repro.launch import steps as ST
 from repro.launch.api import RunSpec
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models.model import build
 from repro.obs.profile import profiled
@@ -41,6 +42,7 @@ from repro.training.train_loop import Trainer, make_train_step
 
 
 def main(argv=None) -> None:
+    use_compile_cache()
     spec = RunSpec.from_argv("train", argv)
     run = spec.start_obs_run()
 

@@ -12,10 +12,10 @@ Three tools (docs/OBSERVABILITY.md §Profiling):
 
   * :func:`record_kernel` times one kernel invocation and books its
     analytic FLOPs/bytes against the roofline hardware model
-    (:mod:`repro.launch.rooflines` constants), reporting the ideal time
-    alongside the measured one. Callers must skip it while tracing —
-    timing a tracer is meaningless and fencing one is an error — via
-    :func:`is_abstract`.
+    (:data:`repro.launch.rooflines.PEAKS` of the running device),
+    reporting the ideal time alongside the measured one. Callers must
+    skip it while tracing — timing a tracer is meaningless and fencing
+    one is an error — via :func:`is_abstract`.
 
   * :func:`live_bytes` / :func:`param_count` / :func:`ebft_live_block_bytes`
     account pytree memory; the EBFT walk uses them to record the
@@ -36,7 +36,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.launch.rooflines import HBM_BW, PEAK_FLOPS
+from repro.launch.rooflines import PEAKS
 from repro.obs import metrics as M
 from repro.obs import trace as T
 
@@ -137,11 +137,14 @@ def record_kernel(name: str, flops: float, bytes_moved: float,
     M.counter(f"{name}/calls").inc()
     M.counter(f"{name}/flops").inc(flops)
     M.counter(f"{name}/bytes").inc(bytes_moved)
-    # ideal time on the modeled chip: the larger of the compute and
-    # memory terms (same two-term model as launch/rooflines.terms)
-    M.gauge(f"{name}/roofline_ideal_s").set(
-        max(flops / PEAK_FLOPS, bytes_moved / HBM_BW)
-    )
+    # ideal time on this chip: the larger of the compute and memory terms
+    # (same two-term model as launch/rooflines.terms); a device the peaks
+    # table does not know gets no figure rather than an assumed one
+    peak = PEAKS.get(jax.devices()[0].device_kind)
+    if peak is not None:
+        M.gauge(f"{name}/roofline_ideal_s").set(
+            max(flops / peak["flops"], bytes_moved / peak["hbm_bw"])
+        )
     return out
 
 
@@ -151,9 +154,8 @@ class ProfiledFn:
 
     Per argument signature (treedef + leaf shapes/dtypes) the wrapper
     lowers and compiles once, timing each stage; subsequent calls hit
-    the cached executable and only record fenced execution time. Falls
-    back to plain first-call timing when the callee exposes no ``lower``
-    (non-jit callables) or AOT lowering fails.
+    the cached executable and only record fenced execution time. The
+    callee must be jitted; a failed lowering or compile raises.
     """
 
     def __init__(self, fn: Callable, name: str):
@@ -186,24 +188,15 @@ class ProfiledFn:
         return out
 
     def _compile(self, sig: Any, args: Tuple) -> Callable:
-        lower = getattr(self.fn, "lower", None)
-        target: Optional[Callable] = None
-        if lower is not None:
-            try:
-                t0 = time.perf_counter()
-                lowered = lower(*args)
-                t_lower = time.perf_counter() - t0
-                t0 = time.perf_counter()
-                target = lowered.compile()
-                t_compile = time.perf_counter() - t0
-                M.gauge(f"{self.name}/lower_s").set(t_lower)
-                M.gauge(f"{self.name}/compile_s").set(t_compile)
-                M.counter(f"{self.name}/compiles").inc()
-            except Exception:
-                target = None  # AOT unsupported for these args: fall back
-        if target is None:
-            target = self.fn
-            M.counter(f"{self.name}/compile_fallbacks").inc()
+        t0 = time.perf_counter()
+        lowered = self.fn.lower(*args)
+        t_lower = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        target = lowered.compile()
+        t_compile = time.perf_counter() - t0
+        M.gauge(f"{self.name}/lower_s").set(t_lower)
+        M.gauge(f"{self.name}/compile_s").set(t_compile)
+        M.counter(f"{self.name}/compiles").inc()
         self._compiled[sig] = target
         return target
 

@@ -60,7 +60,7 @@ def render_text(payload: Dict[str, Any]) -> str:
     if manifest:
         lines.append(f"run: {manifest.get('name', '?')}")
         for key in ("config", "method", "sparsity", "pattern", "git_rev",
-                    "jax_backend", "device_count"):
+                    "platform", "device_kind", "device_count"):
             if key in manifest:
                 lines.append(f"  {key:<13} {manifest[key]}")
 

@@ -2,7 +2,8 @@
 
 ``start_run`` swaps the null tracer/metrics singletons for live ones and
 records the run manifest (what was run: config, sparsity, method, git
-rev, backend). ``Run.finish`` assembles the JSON-summary payload
+rev, platform, device kind and count). ``Run.finish`` assembles the
+JSON-summary payload
 
     {"manifest": ..., "metrics": ..., "trace": ..., **extra}
 
@@ -41,14 +42,12 @@ def git_rev() -> Optional[str]:
         return None
 
 
-def _backend() -> Dict[str, Any]:
-    try:
-        import jax
+def _device() -> Dict[str, Any]:
+    import jax
 
-        return {"jax_backend": jax.default_backend(),
-                "device_count": jax.device_count()}
-    except Exception:  # manifest must never fail the run
-        return {"jax_backend": "unknown", "device_count": 0}
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count()}
 
 
 @dataclasses.dataclass
@@ -131,7 +130,7 @@ def start_run(
         "created_unix": time.time(),
         "argv": list(sys.argv),
         "git_rev": git_rev(),
-        **_backend(),
+        **_device(),
     }
     if config is not None:
         manifest["config"] = config
@@ -174,7 +173,8 @@ _MANIFEST_FIELDS = {
     "name": str,
     "created_unix": (int, float),
     "argv": list,
-    "jax_backend": str,
+    "platform": str,
+    "device_kind": str,
     "device_count": int,
 }
 

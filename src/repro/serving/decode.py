@@ -47,7 +47,9 @@ class Server:
         self._decode = profiled(jax.jit(model.decode_step), "serve/decode")
 
     def _sample(self, logits: jax.Array, rng) -> jax.Array:
-        logits = logits[:, -1]
+        # the head is padded to a multiple of 128 rows; ids past the
+        # vocabulary are not tokens and are never sampled
+        logits = logits[:, -1, : self.model.cfg.vocab_size]
         if self.temperature <= 0:
             return jnp.argmax(logits, axis=-1)
         return jax.random.categorical(rng, logits / self.temperature, axis=-1)
@@ -106,7 +108,8 @@ class Server:
                     state = jax.tree.map(
                         lambda full, one: _slot_update(full, one, slot), state, sub
                     )
-                    tok = int(jnp.argmax(logits[0, -1]))
+                    tok = int(jnp.argmax(
+                        logits[0, -1, : self.model.cfg.vocab_size]))
                     req.out.append(tok)
                     last_tok = last_tok.at[slot, 0].set(tok)
                     remaining[slot] -= 1

@@ -22,7 +22,8 @@ from repro.launch import steps as ST
 from repro.launch import hlo_analysis as HA
 
 cfg = get_config("tiny_dense").replace(num_layers=2)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(2, 4)
 out = {}
 
 # train cell
@@ -95,7 +96,8 @@ from repro.configs.base import ShapeConfig
 from repro.launch import steps as ST, hlo_analysis as HA, rooflines as RL
 
 cfg = get_config("tiny_ssm")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh(4, 2)
 shape = ShapeConfig("t", 64, 8, "train")
 cell = ST.build_train_cell(cfg, shape, mesh, microbatches=1, fsdp=False)
 with mesh:

@@ -374,7 +374,8 @@ def _payload(tuning_section):
     out = {
         "manifest": {"schema": "repro.obs/v1", "name": "t",
                      "created_unix": 0.0, "argv": [],
-                     "jax_backend": "cpu", "device_count": 1},
+                     "platform": "cpu", "device_kind": "cpu",
+                     "device_count": 1},
         "metrics": {},
         "trace": [],
     }
