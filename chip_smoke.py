@@ -140,7 +140,7 @@ def walk(cfg, *, mesh=(1, 1), seq=1024, calib=64, epochs=2, sparsity=0.7,
         f"({zeros / size:.4f} of {size})")
     say(f"walk {mesh[0]}x{mesh[1]}: phase seconds "
         + json.dumps({k: round(v, 3) for k, v in res["phases"].items()})
-        + "; walk compile seconds (first calls) "
+        + "; walk build seconds per phase "
         + json.dumps({k: v and round(v, 3) for k, v in compile_s.items()}))
     return [r.loss_after for r in reports], res["tuned"]
 
@@ -190,13 +190,14 @@ def serve(cfg, *, requests=8, slots=4, prompt=128, new=32, max_len=256,
                 f"{name} logits off the forward pass by {err} "
                 f"(limit {DECODE_RTOL} x {scale})")
 
-    metrics = (res["payload"] or {}).get("metrics", {})
-    compile_s = {k: metrics[k]["last"] for k in
-                 ("serve/prefill/compile_s", "serve/decode/compile_s")
-                 if k in metrics}
+    from repro.obs import trace as OT
+
+    forest = (res["payload"] or {}).get("trace", [])
+    build_s = {f"serve/{name}": OT.totals(forest, f"serve/{name}")[1]
+               for name in ("admit", "step")}
     say(f"serve: ok in {wall:.1f}s wall; {requests} requests x {new} ids "
-        f"in the vocabulary; serving loop {res['seconds']:.2f}s; compile "
-        f"seconds {json.dumps({k: round(v, 3) for k, v in compile_s.items()})}")
+        f"in the vocabulary; serving loop {res['seconds']:.2f}s; build "
+        f"seconds {json.dumps({k: round(v, 3) for k, v in build_s.items()})}")
     say(f"serve: prefill/decode against forward at highest precision, max "
         f"abs error {errs['prefill']:.3e}/{errs['decode']:.3e}, logit scale "
         f"{scale:.3e}, limit {DECODE_RTOL} x scale")

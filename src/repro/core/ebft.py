@@ -62,9 +62,7 @@ from repro.core import reconstruction as R
 from repro.core.pruning import common as C
 from repro.obs import metrics as OM
 from repro.obs import trace as OT
-from repro.obs.profile import (
-    DispatchLedger, FirstCallTimer, ebft_live_block_bytes, live_bytes,
-)
+from repro.obs.profile import DispatchLedger, ebft_live_block_bytes, live_bytes
 from repro.optim.optimizers import adam, apply_updates
 from repro.optim.schedules import plateau_early_stop, plateau_early_stop_device
 from repro.sparsity.sparse_params import apply_masks
@@ -191,12 +189,7 @@ def _make_tune_step(model, kind_rep_i: int, ecfg: EBFTConfig):
     # donate bw: weights + (internal) Adam moments update in place, so the
     # live-block footprint stays at one block (the paper's 16 GB property)
     fused = jax.jit(fused_run, donate_argnums=(0,))
-    # first-call (trace+compile) wall time books onto the compile clock,
-    # which the walk drains per phase — so the walk/tune histogram shows
-    # steady-state and the one-compile-per-block-kind cost lands in
-    # ebft/walk/tune_compile_s (docs/PERF.md)
-    return opt, FirstCallTimer(step), FirstCallTimer(eval_loss), \
-        FirstCallTimer(fused)
+    return opt, step, eval_loss, fused
 
 
 def _stack_microbatches(data: List[Tuple]):
@@ -390,7 +383,8 @@ def finetune(
                  microbatch=ecfg.microbatch, fused=ecfg.fused_epochs,
                  prefetch_depth=ecfg.prefetch_depth,
                  mesh_devices=mesh_devices):
-        student = apply_masks(pruned_params, masks)
+        with OT.span("walk/setup"):
+            student = apply_masks(pruned_params, masks)
         reports: List[BlockReport] = []
         step_cache: Dict = {}
 
@@ -436,19 +430,23 @@ def finetune(
         )
 
         if shared_idx is not None and shared_sites:
-            # the shared block is stored un-stacked (model.get_block returns
-            # the leaves by reference, not a slice) — copy before the donated
-            # fused call so `result`'s own buffers are never invalidated
-            bp = jax.tree.map(jnp.copy, model.get_block(result, shared_idx))
-            mask_bp = model.get_block(masks, shared_idx)
-            tuned, rep = tune_block(
-                model, shared_idx, bp, mask_bp, shared_sites, ecfg, step_cache
-            )
-            reports.append(rep)
-            if log:
-                log(
-                    f"shared block [{rep.kind}] ({len(shared_sites)} site-batches) "
-                    f"E: {rep.loss_before:.3e} -> {rep.loss_after:.3e}"
+            with OT.span("walk/tune", block=shared_idx):
+                # the shared block is stored un-stacked (model.get_block
+                # returns the leaves by reference, not a slice) — copy
+                # before the donated fused call so `result`'s own buffers
+                # are never invalidated
+                bp = jax.tree.map(jnp.copy, model.get_block(result, shared_idx))
+                mask_bp = model.get_block(masks, shared_idx)
+                tuned, rep = tune_block(
+                    model, shared_idx, bp, mask_bp, shared_sites, ecfg,
+                    step_cache
                 )
-            result = model.set_block(result, shared_idx, tuned)
+                reports.append(rep)
+                if log:
+                    log(
+                        f"shared block [{rep.kind}] ({len(shared_sites)} "
+                        f"site-batches) E: {rep.loss_before:.3e} -> "
+                        f"{rep.loss_after:.3e}"
+                    )
+                result = model.set_block(result, shared_idx, tuned)
     return result, reports

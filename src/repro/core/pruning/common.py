@@ -277,8 +277,11 @@ def walk_blocks(
     the ragged list walk are byte-identical to the unsharded behavior.
     Returns the updated student/pruned params.
     """
+    from repro.obs import trace as OT
+
     out_params = params_student if params_student is not None else params
-    batch_all = _make_batches(model.cfg, calib, extra_batch, microbatch)
+    with OT.span("walk/setup"):
+        batch_all = _make_batches(model.cfg, calib, extra_batch, microbatch)
 
     if dual_stream and _uniform_microbatches(batch_all):
         return _walk_blocks_stacked(
@@ -356,14 +359,12 @@ def _walk_blocks_stacked(model, params, out_params, batch_all, visit_fn,
     replicated copy per device."""
     from repro.obs import metrics as OM
     from repro.obs import trace as OT
-    from repro.obs.profile import DispatchLedger, FirstCallTimer, compile_clock
+    from repro.obs.profile import DispatchLedger
 
     sharded = mesh_plan is not None and mesh_plan.active
     ledger = DispatchLedger(
         "ebft/walk", devices=mesh_plan.device_count if sharded else 1
     )
-    clock = compile_clock()
-    clock.take()  # drop compile time booked before this walk started
     n_mb = len(batch_all)
 
     def adv_scan_fn(bp, h_st, pos_st, aux_st, i):
@@ -373,79 +374,66 @@ def _walk_blocks_stacked(model, params, out_params, batch_all, visit_fn,
 
         return jax.lax.map(one, (h_st, pos_st, aux_st))
 
-    # adv_scan recompiles per static block index i; FirstCallTimer books
-    # that first-call cost on the compile clock so the phase histograms
-    # below can report steady-state separately (no fence is added — the
-    # prefetcher's dispatch-ahead overlap is preserved)
-    adv_scan = FirstCallTimer(jax.jit(adv_scan_fn, static_argnames=("i",)))
-    batch_st = jax.tree.map(lambda *xs: jnp.stack(xs), *batch_all)
-    if sharded:
-        batch_st = mesh_plan.put_stacked(batch_st)
+    # one program per static block index i (a per-block build)
+    adv_scan = jax.jit(adv_scan_fn, static_argnames=("i",))
+    # every host statement of a block sits in one phase span: walk/setup
+    # (batches, stream set-up), walk/teacher (the prefetched targets'
+    # fence), walk/tune (the visit and its write-back) and walk/student
+    # (the student advance's dispatch). No span fences: device time
+    # comes from the profiler trace
+    with OT.span("walk/setup"):
+        batch_st = jax.tree.map(lambda *xs: jnp.stack(xs), *batch_all)
+        if sharded:
+            batch_st = mesh_plan.put_stacked(batch_st)
 
     for seg in R.execution_plan(model):
-        # stream setup: one scanned dispatch per (stream, segment)
-        h0_jit = jax.jit(lambda p, bst, h0=seg.h0: jax.lax.map(
-            lambda b: h0(p, b), bst))
-        aux_jit = jax.jit(lambda p, bst, aux=seg.aux: jax.lax.map(
-            lambda b: aux(p, b), bst))
-        ht_st, pos_st = h0_jit(params, batch_st)
-        aux_t_st = aux_jit(params, batch_st)
-        hs_st, _ = h0_jit(out_params, batch_st)
-        aux_s_st = aux_jit(out_params, batch_st)
-        if sharded:
-            # pin the stream layout: activations batch-sharded over the
-            # data axes (GSPMD usually propagates this from batch_st, but
-            # the walk's memory property depends on it, so make it law)
-            ht_st, pos_st, aux_t_st, hs_st, aux_s_st = mesh_plan.put_stacked(
-                (ht_st, pos_st, aux_t_st, hs_st, aux_s_st)
+        with OT.span("walk/setup"):
+            # stream setup: one scanned dispatch per (stream, segment)
+            h0_jit = jax.jit(lambda p, bst, h0=seg.h0: jax.lax.map(
+                lambda b: h0(p, b), bst))
+            aux_jit = jax.jit(lambda p, bst, aux=seg.aux: jax.lax.map(
+                lambda b: aux(p, b), bst))
+            ht_st, pos_st = h0_jit(params, batch_st)
+            aux_t_st = aux_jit(params, batch_st)
+            hs_st, _ = h0_jit(out_params, batch_st)
+            aux_s_st = aux_jit(out_params, batch_st)
+            if sharded:
+                # pin the stream layout: activations batch-sharded over
+                # the data axes (GSPMD usually propagates this from
+                # batch_st, but the walk's memory property depends on
+                # it, so make it law)
+                ht_st, pos_st, aux_t_st, hs_st, aux_s_st = \
+                    mesh_plan.put_stacked(
+                        (ht_st, pos_st, aux_t_st, hs_st, aux_s_st))
+            ledger.dispatch(4)
+
+            pf = TeacherPrefetcher(
+                model, params, seg.visits, adv_scan, ht_st, pos_st,
+                aux_t_st, prefetch_depth, ledger=ledger,
             )
-        ledger.dispatch(4)
 
-        pf = TeacherPrefetcher(
-            model, params, seg.visits, adv_scan, ht_st, pos_st, aux_t_st,
-            prefetch_depth, ledger=ledger,
-        )
-
-        clock.take()  # segment setup compiles (h0/aux) are not a phase
         for k, (i, site) in enumerate(seg.visits):
-            with OT.span("walk/teacher", block=i) as sp_t:
+            with OT.span("walk/teacher", block=i):
                 target_st = pf.get(k)
-            c_teacher = clock.take()
-            bp = model.get_block(out_params, i)
-            ctx = dict(
-                h_st=hs_st, target_st=target_st, pos_st=pos_st,
-                aux_st=aux_s_st, site=site,
-                h_mb=Unstacked(hs_st, n_mb),
-                target_mb=Unstacked(target_st, n_mb),
-                pos_mb=Unstacked(pos_st, n_mb),
-                aux_mb=Unstacked(aux_s_st, n_mb),
-            )
-            with OT.span("walk/tune", block=i) as sp_v:
+            with OT.span("walk/tune", block=i):
+                bp = model.get_block(out_params, i)
+                ctx = dict(
+                    h_st=hs_st, target_st=target_st, pos_st=pos_st,
+                    aux_st=aux_s_st, site=site,
+                    h_mb=Unstacked(hs_st, n_mb),
+                    target_mb=Unstacked(target_st, n_mb),
+                    pos_mb=Unstacked(pos_st, n_mb),
+                    aux_mb=Unstacked(aux_s_st, n_mb),
+                )
                 new_bp = visit_fn(i, bp, ctx)
-            c_tune = clock.take()
-            if new_bp is not None:
-                out_params = model.set_block(out_params, i, new_bp)
-                bp = new_bp
-            with OT.span("walk/student", block=i) as sp_s:
+                if new_bp is not None:
+                    out_params = model.set_block(out_params, i, new_bp)
+                    bp = new_bp
+            with OT.span("walk/student", block=i):
                 hs_st = adv_scan(bp, hs_st, pos_st, aux_s_st, i)
                 ledger.dispatch()
-                sp_s.fence(hs_st)
-            c_student = clock.take()
-            if OT.enabled():
-                # steady-state vs first-call split (docs/PERF.md): the
-                # compile clock holds the trace+compile wall time booked
-                # inside each span; subtracting it keeps walk-phase
-                # percentiles meaningful (block-0 teacher is ~all compile)
-                OM.histogram("ebft/walk/teacher_s").observe(
-                    max(sp_t.duration - c_teacher, 0.0))
-                OM.histogram("ebft/walk/tune_s").observe(
-                    max(sp_v.duration - c_tune, 0.0))
-                OM.histogram("ebft/walk/student_s").observe(
-                    max(sp_s.duration - c_student, 0.0))
-                OM.histogram("ebft/walk/teacher_compile_s").observe(c_teacher)
-                OM.histogram("ebft/walk/tune_compile_s").observe(c_tune)
-                OM.histogram("ebft/walk/student_compile_s").observe(c_student)
-                OM.gauge("ebft/walk/prefetch_inflight").set(pf.in_flight())
+                if OT.enabled():
+                    OM.gauge("ebft/walk/prefetch_inflight").set(pf.in_flight())
     return out_params
 
 

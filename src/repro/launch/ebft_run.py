@@ -78,6 +78,17 @@ class _phase:
         return self.span.fence(value)
 
 
+def _walk_phases(forest) -> Dict[str, float]:
+    """``<phase>``: the walk phase spans' host seconds less the build
+    seconds booked in them; ``<phase>_compile``: those build seconds."""
+    out: Dict[str, float] = {}
+    for phase in ("teacher", "tune", "student"):
+        seconds, build = OT.totals(forest, f"walk/{phase}")
+        out[phase] = max(seconds - build, 0.0)
+        out[f"{phase}_compile"] = build
+    return out
+
+
 def pretrain(model, params, corpus, steps: int, batch: int, seq: int, lr: float,
              say=print):
     opt = adamw(lr)
@@ -263,20 +274,10 @@ def main(argv=None, cfg: Optional[ModelConfig] = None) -> Dict[str, Any]:
                     "walk_device_total": summ.get(
                         "ebft/walk/device_dispatches", {}).get("value"),
                 },
-                # steady-state phase sums, with first-call (trace+compile)
-                # time split out per phase (docs/PERF.md): percentiles of
-                # the *_s histograms now reflect the pipeline, not warm-up
-                "walk_phases": {
-                    **{
-                        phase: summ.get(f"ebft/walk/{phase}_s", {}).get("sum")
-                        for phase in ("teacher", "tune", "student")
-                    },
-                    **{
-                        f"{phase}_compile": summ.get(
-                            f"ebft/walk/{phase}_compile_s", {}).get("sum")
-                        for phase in ("teacher", "tune", "student")
-                    },
-                },
+                # per-phase host seconds of the walk, with the program-build
+                # time JAX reported inside each phase's spans split out
+                # (docs/PERF.md); device time is the profiler trace's
+                "walk_phases": _walk_phases(run.tracer.tree()),
                 # tile-plan autotuner accounting (docs/PERF.md): a warm
                 # cache run must show misses == searches == 0 and
                 # search_s == 0.0 (CI gates this via

@@ -6,13 +6,13 @@ package provides the three primitives every driver/benchmark uses
 instead of ``print()`` + ``time.time()`` (DESIGN.md §8,
 docs/OBSERVABILITY.md):
 
-  * :mod:`repro.obs.trace`   — nested wall-time spans with optional
-    ``jax.block_until_ready`` fencing, so device work is attributed to
-    the span that launched it::
+  * :mod:`repro.obs.trace`   — nested host-time spans that are also
+    ``jax.profiler`` annotations, so under a profiler session each span
+    sits on the device trace's clock beside the programs it launched::
 
         from repro.obs import trace
-        with trace.span("ebft/block", index=i) as sp:
-            out = sp.fence(step(...))   # device fence at attribution point
+        with trace.span("walk/tune", block=i):
+            out = step(...)             # host time; device time: the trace
 
   * :mod:`repro.obs.metrics` — counters / gauges / histograms /
     time-series with a JSON summary and JSONL event stream::
@@ -26,10 +26,10 @@ docs/OBSERVABILITY.md):
     (roofline model from :mod:`repro.launch.rooflines`), and pytree
     byte/param accounting for the paper's live-block-memory claim.
 
-Everything is **off by default**: the module-level tracer/registry are
-null singletons whose methods allocate nothing, so instrumentation in
-hot paths is free until :func:`repro.obs.run.start_run` swaps in live
-objects. Instrumentation is host-side only — spans and metric updates
+Everything is **off by default**: the module-level registry is a null
+singleton whose methods allocate nothing, and the null tracer's spans
+are bare profiler annotations (about a microsecond each), until
+:func:`repro.obs.run.start_run` swaps in live objects. Instrumentation is host-side only — spans and metric updates
 must never be traced into jitted code (kernel hooks skip themselves
 when they see abstract tracers).
 

@@ -22,11 +22,9 @@ Three tools (docs/OBSERVABILITY.md §Profiling):
     paper's streaming claim (peak live block = weights + masks + two
     f32 Adam moments) as a measurable gauge.
 
-  * :class:`FirstCallTimer` + :class:`CompileClock` attribute first-call
-    (trace+compile) wall time to the region that triggered it without
-    fencing — the EBFT walk drains the clock per phase so the
-    ``ebft/walk/*_s`` histograms report steady-state and compile cost
-    lands in ``ebft/walk/*_compile_s`` (docs/PERF.md).
+Program-build time of the walk and of serving is not timed here: a live
+run books JAX's own build events onto the open span
+(``trace.Tracer.book_build``).
 """
 from __future__ import annotations
 
@@ -204,73 +202,3 @@ class ProfiledFn:
 def profiled(fn: Callable, name: str) -> ProfiledFn:
     """Wrap ``fn`` (ideally ``jax.jit``-ed) with compile/exec profiling."""
     return ProfiledFn(fn, name)
-
-
-# ---------------------------------------------------------------------------
-# first-call (trace+compile) attribution for the walk-phase histograms
-# ---------------------------------------------------------------------------
-class CompileClock:
-    """Accumulates first-call wall time booked by :class:`FirstCallTimer`;
-    a consumer (the EBFT walk) ``take()``s the pending total per phase so
-    phase histograms can report steady-state and compile separately
-    (``ebft/walk/{phase}_s`` vs ``{phase}_compile_s``, docs/PERF.md)."""
-
-    __slots__ = ("_pending",)
-
-    def __init__(self) -> None:
-        self._pending = 0.0
-
-    def add(self, dt: float) -> None:
-        self._pending += dt
-
-    def take(self) -> float:
-        dt, self._pending = self._pending, 0.0
-        return dt
-
-
-_CLOCK = CompileClock()
-
-
-def compile_clock() -> CompileClock:
-    """The process-wide clock the walk drains between phases."""
-    return _CLOCK
-
-
-class FirstCallTimer:
-    """Times the *synchronous* part of the first call per argument
-    signature and books it on the :class:`CompileClock`.
-
-    jit dispatch is async: a warm call returns as soon as execution is
-    enqueued, but the FIRST call for a signature traces and compiles
-    synchronously before enqueueing. Timing that call without fencing
-    therefore isolates trace+compile from device execution — crucially
-    *without* adding a host sync, so wrapping the prefetcher's dispatches
-    does not serialize the pipeline it measures. Non-array leaves (e.g. a
-    static block index) participate in the signature by value, matching
-    jit's own cache keying.
-    """
-
-    __slots__ = ("fn", "_seen")
-
-    def __init__(self, fn: Callable):
-        self.fn = fn
-        self._seen: set = set()
-
-    def _sig(self, args: Tuple, kw: Dict[str, Any]) -> Any:
-        leaves, treedef = jax.tree.flatten((args, kw))
-        return treedef, tuple(
-            (np.shape(x), str(x.dtype)) if hasattr(x, "dtype") else ("val", x)
-            for x in leaves
-        )
-
-    def __call__(self, *args, **kw):
-        if not T.enabled():
-            return self.fn(*args, **kw)
-        sig = self._sig(args, kw)
-        if sig in self._seen:
-            return self.fn(*args, **kw)
-        self._seen.add(sig)
-        t0 = time.perf_counter()
-        out = self.fn(*args, **kw)
-        _CLOCK.add(time.perf_counter() - t0)
-        return out

@@ -9,7 +9,9 @@ JSON-summary payload
 
 optionally writes it (``summary_path`` — this is how ``BENCH_ebft.json``
 is produced), closes sinks, and restores the null singletons, so runs
-never leak state into later code (tests rely on this).
+never leak state into later code (tests rely on this). While a run is
+live, one ``jax.monitoring`` listener books JAX's program-build events
+onto the innermost open span (``Tracer.book_build``).
 
 ``validate_payload`` is the manifest schema check CI gates artifacts on.
 """
@@ -89,6 +91,10 @@ class Run:
             self._finished = True
             if self.jsonl is not None:
                 self.jsonl.close()
+            import jax.monitoring
+
+            jax.monitoring.unregister_event_duration_listener(
+                self.tracer.book_build)
             global _CURRENT
             if _CURRENT is self:
                 _CURRENT = None
@@ -159,6 +165,9 @@ def start_run(
         jsonl=jsonl,
         console=ConsoleSink() if console else None,
     )
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(tracer.book_build)
     T.set_tracer(tracer)
     M.set_registry(registry)
     _CURRENT = run

@@ -1,16 +1,27 @@
-"""Span tracer: nested wall-time spans with optional device fencing.
+"""Span tracer: nested host-time spans that are also profiler annotations.
 
-A :class:`Span` measures host wall-time between ``__enter__`` and
-``__exit__`` on the monotonic clock. Because jax dispatch is async, a
-span around ``step(...)`` alone would only time the *launch*; call
-``sp.fence(value)`` on the result to ``jax.block_until_ready`` it inside
-the span, attributing the device work to the right place.
+Every :func:`span` enters a ``jax.profiler.TraceAnnotation`` of the same
+name and attributes, so under a profiler session (``jax.profiler.trace``
+or ``start_trace``) the span is a host event on the device trace's clock,
+beside the programs it dispatched; with no session the annotation records
+nothing and costs about a microsecond. Attributes carry identifiers
+(``block=i``, ``uid=``), never values that need a device sync.
 
-The module-level :func:`span` dispatches to the current tracer — a
-:class:`NullTracer` by default whose ``span()`` returns a stateless
-no-op singleton (zero allocation, reentrant), so instrumented hot paths
-cost one attribute lookup when observability is off. ``start_run``
-(repro.obs.run) installs a live :class:`Tracer`.
+A live :class:`Span` additionally measures host wall-time between
+``__enter__`` and ``__exit__`` on the monotonic clock. jax dispatch is
+async, so that is host time: the device time of the work a span launched
+comes from the profiler trace. ``sp.fence(value)`` blocks on ``value``
+inside the span, for callers that want a span to wait for its device work
+(a pipeline phase, a kernel timing); the walk and serving never fence.
+
+The module-level :func:`span` dispatches to the current tracer: a
+:class:`NullTracer` by default, whose spans wrap only the annotation, or a
+live :class:`Tracer` installed by ``start_run`` (repro.obs.run), which
+also keeps the span tree and calls its JSONL emitters at every span end.
+While a run is live, :meth:`Tracer.book_build` (a ``jax.monitoring``
+listener ``start_run`` installs) adds JAX's own program-build durations
+to the innermost open span as ``build_s``, and counts the programs built
+(lowered) as ``builds``.
 
 Spans must be strictly nested (they form a tree); the tracer keeps the
 open-span stack and the list of completed roots. ``Tracer.tree()``
@@ -19,15 +30,31 @@ returns the JSON-ready forest the report CLI renders.
 from __future__ import annotations
 
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+# JAX's duration events that together are building a program: tracing to
+# a jaxpr, lowering to MLIR, the backend compile, or a read from the
+# persistent compilation cache in its place. Lowering happens once per
+# program built (a jitted function traced inside another is traced again,
+# but lowered only as part of the outer program), so it counts builds.
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BUILD_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    LOWER_EVENT,
+    "/jax/core/compile/backend_compile_duration",
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+)
 
 
-class Span:
+class Span(TraceAnnotation):
     """One timed region. Context manager; re-entry is not supported."""
 
     __slots__ = ("name", "attrs", "start", "duration", "children", "_tracer")
 
     def __init__(self, name: str, attrs: Dict[str, Any], tracer: "Tracer"):
+        super().__init__(name, **attrs)
         self.name = name
         self.attrs = attrs
         self.start: float = 0.0
@@ -36,7 +63,8 @@ class Span:
         self._tracer = tracer
 
     def set(self, **attrs) -> "Span":
-        """Attach attributes discovered while the span is open."""
+        """Attach attributes discovered while the span is open (kept in
+        the span tree; the profiler event keeps those given at opening)."""
         self.attrs.update(attrs)
         return self
 
@@ -52,6 +80,7 @@ class Span:
 
     # -- context manager ------------------------------------------------
     def __enter__(self) -> "Span":
+        super().__enter__()
         self._tracer._push(self)
         self.start = self._tracer.clock()
         return self
@@ -59,6 +88,7 @@ class Span:
     def __exit__(self, *exc) -> bool:
         self.duration = self._tracer.clock() - self.start
         self._tracer._pop(self)
+        super().__exit__(*exc)
         return False
 
     def asdict(self) -> Dict[str, Any]:
@@ -74,8 +104,8 @@ class Span:
         return d
 
 
-class _NullSpan:
-    """Stateless no-op span — one shared instance, safe to re-enter."""
+class _NullSpan(TraceAnnotation):
+    """The profiler annotation alone: no clock, no tree, no state."""
 
     __slots__ = ()
     name = ""
@@ -89,15 +119,6 @@ class _NullSpan:
 
     def fence(self, value):
         return value
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-
-NULL_SPAN = _NullSpan()
 
 
 class Tracer:
@@ -117,6 +138,15 @@ class Tracer:
     def add_emitter(self, fn: Callable[[Dict[str, Any]], None]) -> None:
         """``fn(event_dict)`` is called at every span end (JSONL sinks)."""
         self._emit.append(fn)
+
+    def book_build(self, event: str, duration: float, **_kw) -> None:
+        """``jax.monitoring`` duration listener: books a program-build
+        event onto the innermost open span (none open: dropped)."""
+        if event in BUILD_EVENTS and self._stack:
+            attrs = self._stack[-1].attrs
+            attrs["build_s"] = attrs.get("build_s", 0.0) + float(duration)
+            if event == LOWER_EVENT:
+                attrs["builds"] = attrs.get("builds", 0) + 1
 
     # -- stack maintenance (called by Span) -----------------------------
     def _push(self, sp: Span) -> None:
@@ -150,13 +180,13 @@ class Tracer:
 
 
 class NullTracer:
-    """Default tracer: observability off, everything is a no-op."""
+    """Default tracer: observability off; spans are bare annotations."""
 
     enabled = False
     roots: List[Span] = []
 
     def span(self, name: str, **attrs) -> _NullSpan:
-        return NULL_SPAN
+        return _NullSpan(name, **attrs)
 
     def add_emitter(self, fn) -> None:
         pass
@@ -180,9 +210,30 @@ def set_tracer(tracer: Optional[Tracer]) -> None:
 
 
 def span(name: str, **attrs):
-    """Open a span on the current tracer (no-op when disabled)."""
+    """Open a span on the current tracer (an annotation alone when off)."""
     return _TRACER.span(name, **attrs)
 
 
 def enabled() -> bool:
     return _TRACER.enabled
+
+
+def totals(forest: Iterable[Dict[str, Any]], name: str) -> Tuple[float, float]:
+    """(seconds, build seconds) summed over every span called ``name`` in
+    a ``Tracer.tree()`` forest; a span's build seconds include those its
+    descendants booked. Spans of one name are assumed not to nest."""
+    seconds = build = 0.0
+
+    def subtree_build(node) -> float:
+        return (node.get("attrs", {}).get("build_s", 0.0)
+                + sum(subtree_build(c) for c in node.get("children", ())))
+
+    stack = list(forest)
+    while stack:
+        node = stack.pop()
+        if node["name"] == name:
+            seconds += node["duration_s"]
+            build += subtree_build(node)
+        else:
+            stack.extend(node.get("children", ()))
+    return seconds, build

@@ -22,7 +22,6 @@ import numpy as np
 
 from repro.obs import metrics as OM
 from repro.obs import trace as OT
-from repro.obs.profile import profiled
 
 
 @dataclasses.dataclass
@@ -31,6 +30,10 @@ class Request:
     prompt: np.ndarray  # (S,) int32
     max_new: int = 32
     out: Optional[List[int]] = None
+    # host time.perf_counter_ns() when the request left the queue, and
+    # when each id of ``out`` reached the host (one per id, in order)
+    admitted_ns: int = 0
+    token_ns: Optional[List[int]] = None
 
 
 class Server:
@@ -41,10 +44,8 @@ class Server:
         self.max_len = max_len
         self.temperature = temperature
 
-        # profiled: compile time vs execution time per (batch, len) bucket
-        # (zero-overhead passthrough while observability is off)
-        self._prefill = profiled(jax.jit(model.prefill), "serve/prefill")
-        self._decode = profiled(jax.jit(model.decode_step), "serve/decode")
+        self._prefill = jax.jit(model.prefill)
+        self._decode = jax.jit(model.decode_step)
 
     def _sample(self, logits: jax.Array, rng) -> jax.Array:
         # the head is padded to a multiple of 128 rows; ids past the
@@ -97,48 +98,59 @@ class Server:
             for slot in range(self.B):
                 if active[slot] is None and queue:
                     req = queue.pop(0)
-                    active[slot] = req
-                    req.out = []
-                    remaining[slot] = req.max_new
-                    # single-sequence prefill into this slot
-                    sub = self.model.init_serve_state(1, self.max_len)
-                    logits, sub = self._prefill(
-                        self.params, {"tokens": jnp.asarray(req.prompt[None])}, sub
-                    )
-                    state = jax.tree.map(
-                        lambda full, one: _slot_update(full, one, slot), state, sub
-                    )
-                    tok = int(jnp.argmax(
-                        logits[0, -1, : self.model.cfg.vocab_size]))
-                    req.out.append(tok)
-                    last_tok = last_tok.at[slot, 0].set(tok)
-                    remaining[slot] -= 1
+                    with OT.span("serve/admit", uid=req.uid):
+                        req.admitted_ns = time.perf_counter_ns()
+                        active[slot] = req
+                        req.out = []
+                        req.token_ns = []
+                        remaining[slot] = req.max_new
+                        # single-sequence prefill into this slot
+                        sub = self.model.init_serve_state(1, self.max_len)
+                        logits, sub = self._prefill(
+                            self.params, {"tokens": jnp.asarray(req.prompt[None])},
+                            sub
+                        )
+                        state = jax.tree.map(
+                            lambda full, one: _slot_update(full, one, slot),
+                            state, sub
+                        )
+                        tok = int(jnp.argmax(
+                            logits[0, -1, : self.model.cfg.vocab_size]))
+                        req.token_ns.append(time.perf_counter_ns())
+                        req.out.append(tok)
+                        last_tok = last_tok.at[slot, 0].set(tok)
+                        remaining[slot] -= 1
             if obs_on:
                 OM.gauge("serve/queue_depth").set(len(queue))
 
+        # every host statement of a decode iteration sits in serve/step
+        # (serve/sync: the token reads); admissions are serve/admit
         with OT.span("serve/batch", requests=len(requests), slots=self.B):
             admit()
             while any(a is not None for a in active):
-                if obs_on:
-                    # occupancy: fraction of slots doing useful decode work
-                    OM.histogram("serve/batch_occupancy").observe(
-                        sum(1 for a in active if a is not None) / self.B
-                    )
-                rng, sub = jax.random.split(rng)
-                logits, state = self._decode(self.params, last_tok, state)
-                tok = self._sample(logits, sub)
-                for slot in range(self.B):
-                    req = active[slot]
-                    if req is None:
-                        continue
-                    t = int(tok[slot])
-                    req.out.append(t)
-                    remaining[slot] -= 1
-                    tokens_out += 1
-                    if remaining[slot] <= 0:
-                        results[req.uid] = req.out
-                        active[slot] = None
-                last_tok = tok[:, None].astype(jnp.int32)
+                with OT.span("serve/step"):
+                    if obs_on:
+                        # occupancy: fraction of slots doing useful decode work
+                        OM.histogram("serve/batch_occupancy").observe(
+                            sum(1 for a in active if a is not None) / self.B
+                        )
+                    rng, sub = jax.random.split(rng)
+                    logits, state = self._decode(self.params, last_tok, state)
+                    tok = self._sample(logits, sub)
+                    with OT.span("serve/sync"):
+                        for slot in range(self.B):
+                            req = active[slot]
+                            if req is None:
+                                continue
+                            t = int(tok[slot])
+                            req.token_ns.append(time.perf_counter_ns())
+                            req.out.append(t)
+                            remaining[slot] -= 1
+                            tokens_out += 1
+                            if remaining[slot] <= 0:
+                                results[req.uid] = req.out
+                                active[slot] = None
+                    last_tok = tok[:, None].astype(jnp.int32)
                 admit()
             if obs_on:
                 dt = time.perf_counter() - t_start

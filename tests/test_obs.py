@@ -38,7 +38,9 @@ def test_null_mode_no_events_no_state():
     assert not OT.enabled() and not OM.enabled()
     with OT.span("outer", a=1) as sp:
         with OT.span("inner") as inner:
-            assert inner is sp is OT.NULL_SPAN  # one shared instance
+            # the profiler annotation alone: no clock, no tree, no slots
+            assert isinstance(inner, jax.profiler.TraceAnnotation)
+            assert type(inner) is type(sp) and not type(sp).__slots__
         sp.set(b=2)
         assert sp.fence(42) == 42  # fence is identity when off
     OM.counter("c").inc(5)
@@ -231,35 +233,145 @@ def test_profiled_fn_passthrough_when_disabled_or_traced():
     assert "test/off/exec_s" not in s and "test/off/compiles" not in s
 
 
-def test_first_call_timer_books_compile_once_per_signature():
-    from repro.obs.profile import FirstCallTimer, compile_clock
-
+def test_build_events_book_onto_innermost_span():
+    """The run's monitoring listener books JAX's program-build events
+    onto the innermost open span; a warm call books none."""
     start_run("t", console=False)
-    clock = compile_clock()
-    clock.take()  # drain anything earlier tests left pending
-    timed = FirstCallTimer(jax.jit(lambda x, i: x + i, static_argnames="i"))
+    f = jax.jit(lambda x: x * 3.0 - 1.0)
     x = jnp.arange(4.0)
+    with OT.span("outer") as outer:
+        with OT.span("inner") as inner:
+            f(x).block_until_ready()
+    assert inner.attrs["builds"] == 1 and inner.attrs["build_s"] > 0.0
+    assert "build_s" not in outer.attrs and "builds" not in outer.attrs
+    with OT.span("again") as again:
+        f(x).block_until_ready()
+    assert again.attrs == {}
+    # the listener leaves with the run
+    run = current_run()
+    run.finish()
+    import jax._src.monitoring as monitoring
 
-    timed(x, i=0)
-    assert clock.take() > 0.0          # first call: trace+compile booked
-    timed(x, i=0)
-    assert clock.take() == 0.0         # warm call books nothing
-    # a different static value is a different jit cache entry, so the
-    # signature must treat non-array leaves by value
-    timed(x, i=1)
-    assert clock.take() > 0.0
-    # clock drains: a second take with nothing new is zero
-    assert clock.take() == 0.0
+    assert run.tracer.book_build not in \
+        monitoring._event_duration_secs_listeners
 
 
-def test_first_call_timer_passthrough_when_disabled():
-    from repro.obs.profile import FirstCallTimer, compile_clock
+def test_totals_sums_spans_and_subtree_builds():
+    forest = [{"name": "w", "duration_s": 9.0, "children": [
+        {"name": "walk/tune", "duration_s": 2.0, "attrs": {"build_s": 0.5},
+         "children": [{"name": "ebft/block", "duration_s": 1.5,
+                       "attrs": {"build_s": 1.0}}]},
+        {"name": "walk/tune", "duration_s": 1.0},
+        {"name": "walk/student", "duration_s": 0.25}]}]
+    assert OT.totals(forest, "walk/tune") == (3.0, 1.5)
+    assert OT.totals(forest, "walk/student") == (0.25, 0.0)
+    assert OT.totals(forest, "walk/teacher") == (0.0, 0.0)
 
-    clock = compile_clock()
-    clock.take()
-    timed = FirstCallTimer(jax.jit(lambda x: x * 2.0))
-    assert float(timed(jnp.float32(3.0))) == 6.0  # obs off: raw call
-    assert clock.take() == 0.0
+
+# ---------------------------------------------------------------------------
+# spans on the profiler clock
+# ---------------------------------------------------------------------------
+def _host_events(trace_dir):
+    """(name, start_ns, end_ns, stats) of every host event of the trace."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+@pytest.mark.parametrize("live", [False, True], ids=["null", "live"])
+def test_span_is_a_profiler_host_event(tmp_path, live):
+    if live:
+        start_run("t", console=False)
+    f = jax.jit(lambda x: x + 1.0)
+    f(jnp.ones(3)).block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        with OT.span("walk/tune", block=3):
+            with OT.span("serve/sync"):
+                f(jnp.ones(3)).block_until_ready()
+    events = _host_events(tmp_path)
+    (_, t0, t1, stats), = [e for e in events if e[0] == "walk/tune"]
+    assert stats == {"block": 3}
+    (_, s0, s1, _), = [e for e in events if e[0] == "serve/sync"]
+    assert t0 <= s0 <= s1 <= t1
+    assert OT.enabled() == live and bool(OT.get_tracer().tree()) == live
+
+
+def _tiny(arch="tiny_dense"):
+    from repro.configs import get_config
+    from repro.models.model import build
+
+    model = build(get_config(arch))
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def _names(nodes):
+    return [(n["name"], n.get("attrs", {}).get("block")) for n in nodes]
+
+
+def test_finetune_records_walk_phase_spans():
+    """One finetune: walk/setup before the first block, then teacher,
+    tune and student once for each block, in that order."""
+    import numpy as np
+
+    from repro.core import ebft
+    from repro.core.masks import prune
+
+    model, params = _tiny()
+    calib = np.random.default_rng(0).integers(
+        0, model.cfg.vocab_size, size=(16, 32)).astype(np.int32)
+    masks, pruned = prune(model, params, calib, method="magnitude",
+                          sparsity=0.5)
+    run = start_run("t", console=False)
+    _, reports = ebft.finetune(model, params, pruned, masks, calib,
+                               ebft.EBFTConfig(epochs=1, microbatch=8))
+    walk, = run.tracer.tree()
+    assert walk["name"] == "ebft/walk"
+    names = _names(walk["children"])
+    first = names.index(("walk/teacher", 0))
+    assert first > 0 and all(n == "walk/setup" for n, _ in names[:first])
+    blocks = [r.index for r in reports]
+    assert blocks == list(range(model.num_blocks))
+    assert [n for n in names[first:] if n[0] != "walk/setup"] == [
+        (f"walk/{phase}", i) for i in blocks
+        for phase in ("teacher", "tune", "student")]
+    # the tune step and the per-block stream advances (the teacher's
+    # dispatch builds the program the student's reuses) were built inside
+    # the phases that called them
+    assert OT.totals([walk], "walk/tune")[1] > 0
+    assert OT.totals([walk], "walk/teacher")[1] > 0
+
+
+def test_serve_records_admit_step_and_sync_spans():
+    import numpy as np
+
+    from repro.serving.decode import Request, Server
+
+    model, params = _tiny()
+    rng = np.random.default_rng(3)
+    reqs = [Request(uid=u, prompt=rng.integers(0, 500, size=(8,)).astype(np.int32),
+                    max_new=n) for u, n in ((7, 4), (8, 3))]
+    run = start_run("t", console=False)
+    Server(model, params, batch_size=1, max_len=32).serve(reqs)
+    batch, = run.tracer.tree()
+    kids = batch["children"]
+    admits = [k for k in kids if k["name"] == "serve/admit"]
+    steps = [k for k in kids if k["name"] == "serve/step"]
+    assert [a["attrs"]["uid"] for a in admits] == [7, 8]
+    # one slot: the first id comes with the prefill, one step per id after
+    assert len(steps) == (4 - 1) + (3 - 1)
+    assert {k["name"] for k in kids} == {"serve/admit", "serve/step"}
+    for st in steps:
+        assert [c["name"] for c in st["children"]] == ["serve/sync"]
 
 
 def test_is_abstract_and_live_bytes():

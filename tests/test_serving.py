@@ -65,3 +65,15 @@ def test_sparse_params_serve_unchanged(served):
     prompts = [rng.integers(0, 500, size=(8,)).astype(np.int32)]
     outs = Server(model, pruned, batch_size=1, max_len=32).generate(prompts, max_new=4)
     assert len(outs[0]) == 4
+
+
+def test_serve_records_token_times(served):
+    """One host timestamp per output id, in order, after admission."""
+    model, params = served
+    rng = np.random.default_rng(4)
+    req = Request(uid=0, prompt=rng.integers(0, 500, size=(10,)).astype(np.int32),
+                  max_new=6)
+    out = Server(model, params, batch_size=2, max_len=64).serve([req])[0]
+    assert len(req.token_ns) == len(out) == 6
+    assert req.admitted_ns < req.token_ns[0]
+    assert all(a < b for a, b in zip(req.token_ns, req.token_ns[1:]))

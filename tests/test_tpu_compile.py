@@ -114,7 +114,7 @@ def test_ebft_tune_step_fits_one_chip(one_chip):
         return jax.tree.map(
             lambda s: _spec(s.shape, s.dtype, one_chip), tree)
 
-    fused = ebft._make_tune_step(model, 0, ebft.EBFTConfig(epochs=2))[3].fn
+    fused = ebft._make_tune_step(model, 0, ebft.EBFTConfig(epochs=2))[3]
     compiled = fused.lower(place(block), place(block), place(h), place(h),
                            place(pos), place(aux)).compile()
     mem = compiled.memory_analysis()
