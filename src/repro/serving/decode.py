@@ -7,8 +7,11 @@ allocated once (B, max_len) and slots are recycled — the paper-relevant
 part is that sparse (EBFT-fine-tuned) weights drop straight in, since the
 serve path reads the same param pytree as training.
 
-Decode sampling is greedy or temperature; everything is jit-compiled once
-per (batch, len) bucket.
+A decode step is one jitted program, sampling included (greedy or
+temperature), and the host reads each step's ids in one transfer. While
+every active slot needs another id, the next step is dispatched before
+that read, so the device runs the steps back to back.
+Everything is jit-compiled once per (batch, len) bucket.
 """
 from __future__ import annotations
 
@@ -45,7 +48,9 @@ class Server:
         self.temperature = temperature
 
         self._prefill = jax.jit(model.prefill)
-        self._decode = jax.jit(model.decode_step)
+        # one program per decode step, rng split to sampled ids; a lambda,
+        # so it lowers as ``jit__lambda_``
+        self._decode = jax.jit(lambda p, t, s, r: self._step(p, t, s, r))
 
     def _sample(self, logits: jax.Array, rng) -> jax.Array:
         # the head is padded to a multiple of 128 rows; ids past the
@@ -54,6 +59,12 @@ class Server:
         if self.temperature <= 0:
             return jnp.argmax(logits, axis=-1)
         return jax.random.categorical(rng, logits / self.temperature, axis=-1)
+
+    def _step(self, params, tok, state, rng):
+        """(B, 1) int32 ids -> (the next (B, 1) int32 ids, state, rng)."""
+        rng, sub = jax.random.split(rng)
+        logits, state = self.model.decode_step(params, tok, state)
+        return self._sample(logits, sub)[:, None].astype(jnp.int32), state, rng
 
     def generate(self, prompts: List[np.ndarray], max_new: int = 32, seed: int = 0):
         """One-shot batched generation (prompts padded to a bucket)."""
@@ -67,15 +78,12 @@ class Server:
         batch = {"tokens": jnp.asarray(toks)}
         logits, state = self._prefill(self.params, batch, state)
         rng = jax.random.PRNGKey(seed)
-        outs = [[] for _ in range(B)]
-        tok = self._sample(logits, rng)
-        for step in range(max_new):
-            for i in range(B):
-                outs[i].append(int(tok[i]))
-            rng, sub = jax.random.split(rng)
-            logits, state = self._decode(self.params, tok[:, None].astype(jnp.int32), state)
-            tok = self._sample(logits, sub)
-        return outs
+        tok = self._sample(logits, rng)[:, None].astype(jnp.int32)
+        cols = [tok]
+        for _ in range(max_new - 1):
+            tok, state, rng = self._decode(self.params, tok, state, rng)
+            cols.append(tok)
+        return np.asarray(jnp.concatenate(cols, axis=1))[:, :max_new].tolist()
 
     # ------------------------------------------------------------------
     def serve(self, requests: List[Request], seed: int = 0) -> Dict[int, List[int]]:
@@ -96,7 +104,7 @@ class Server:
         def admit():
             nonlocal state, last_tok
             for slot in range(self.B):
-                if active[slot] is None and queue:
+                while active[slot] is None and queue:
                     req = queue.pop(0)
                     with OT.span("serve/admit", uid=req.uid):
                         req.admitted_ns = time.perf_counter_ns()
@@ -120,37 +128,53 @@ class Server:
                         req.out.append(tok)
                         last_tok = last_tok.at[slot, 0].set(tok)
                         remaining[slot] -= 1
+                        if remaining[slot] <= 0:  # the prefill's id was all
+                            results[req.uid] = req.out
+                            active[slot] = None
             if obs_on:
                 OM.gauge("serve/queue_depth").set(len(queue))
 
         # every host statement of a decode iteration sits in serve/step
-        # (serve/sync: the token reads); admissions are serve/admit
+        # (serve/sync: the token read); admissions are serve/admit. While
+        # every active slot needs another token after this step, the next
+        # step is dispatched before this one's read ("ahead"), so the chip
+        # runs steps back to back; otherwise the read, the freed slots and
+        # the admissions come first
         with OT.span("serve/batch", requests=len(requests), slots=self.B):
             admit()
+            nxt = None  # (B, 1) ids of a step dispatched ahead, not yet read
             while any(a is not None for a in active):
-                with OT.span("serve/step"):
+                ahead = all(remaining[slot] > 1
+                            for slot, req in enumerate(active) if req is not None)
+                with OT.span("serve/step", ahead=int(ahead)):
                     if obs_on:
                         # occupancy: fraction of slots doing useful decode work
                         OM.histogram("serve/batch_occupancy").observe(
                             sum(1 for a in active if a is not None) / self.B
                         )
-                    rng, sub = jax.random.split(rng)
-                    logits, state = self._decode(self.params, last_tok, state)
-                    tok = self._sample(logits, sub)
+                        if ahead:
+                            OM.counter("serve/steps_ahead").inc()
+                    if nxt is None:  # the step before did not dispatch this one
+                        nxt, state, rng = self._decode(self.params, last_tok, state, rng)
+                    last_tok = nxt
+                    if ahead:
+                        nxt, state, rng = self._decode(self.params, last_tok, state, rng)
+                    else:
+                        nxt = None
                     with OT.span("serve/sync"):
+                        ids = np.asarray(last_tok)  # obs: sync-ok (the step's ids)
+                        t = time.perf_counter_ns()
                         for slot in range(self.B):
                             req = active[slot]
                             if req is None:
                                 continue
-                            t = int(tok[slot])
-                            req.token_ns.append(time.perf_counter_ns())
-                            req.out.append(t)
+                            req.token_ns.append(t)
+                            req.out.append(int(ids[slot, 0]))
                             remaining[slot] -= 1
                             tokens_out += 1
                             if remaining[slot] <= 0:
                                 results[req.uid] = req.out
                                 active[slot] = None
-                    last_tok = tok[:, None].astype(jnp.int32)
                 admit()
             if obs_on:
                 dt = time.perf_counter() - t_start

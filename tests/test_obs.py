@@ -374,6 +374,25 @@ def test_serve_records_admit_step_and_sync_spans():
         assert [c["name"] for c in st["children"]] == ["serve/sync"]
 
 
+
+def test_serve_dispatches_ahead_but_never_past_a_last_step():
+    """One slot, max_new 4 then 3: every step but a request's last
+    dispatches the next step before its read, and the counter counts them."""
+    import numpy as np
+
+    from repro.serving.decode import Request, Server
+
+    model, params = _tiny()
+    rng = np.random.default_rng(3)
+    reqs = [Request(uid=u, prompt=rng.integers(0, 500, size=(8,)).astype(np.int32),
+                    max_new=n) for u, n in ((7, 4), (8, 3))]
+    run = start_run("t", console=False)
+    Server(model, params, batch_size=1, max_len=32).serve(reqs)
+    batch, = run.tracer.tree()
+    steps = [k for k in batch["children"] if k["name"] == "serve/step"]
+    assert [st["attrs"]["ahead"] for st in steps] == [1, 1, 0, 1, 0]
+    assert OM.summary()["serve/steps_ahead"]["value"] == 3
+
 def test_is_abstract_and_live_bytes():
     assert not is_abstract(jnp.ones(3), {"a": 1.0})
     seen = []
