@@ -18,9 +18,17 @@ it fits. Matmuls run at ``Precision.HIGHEST``.
 
 Departures: the norms use eps 1e-6 as the program computes them
 (``mamba_ssm`` defaults to 1e-5), and the head is untied, as the program
-has it. ``init`` makes seeded weights in ``repro.models.ssm``'s layout,
-drawn as ``mamba_ssm`` initialises them (A in [1, 16], dt in
-[1e-3, 1e-1]).
+has it. ``init`` makes seeded weights in ``repro.models.ssm``'s layout (leaves
+stacked over layers under ``blocks``), drawn as ``mamba_ssm``
+initialises them (A in [1, 16], dt in [1e-3, 1e-1]).
+
+The module also carries the block's work counts, which
+``bench/harness/flops.py`` composes: a block's forward per token is 2 x
+its matmul weights, plus the depthwise conv, 2 * K * (d_inner + 2N), and
+the recurrence's outer product B (x) x and contraction C . s, 2 * 2 * H
+* N * P (the decay multiply is not counted). Norms, biases and
+activations are not counted. A block keeps a state, not K/V per
+position.
 """
 from __future__ import annotations
 
@@ -33,6 +41,9 @@ import jax.numpy as jnp
 HI = jax.lax.Precision.HIGHEST
 EPS = 1e-6
 TIME_CHUNK = 64  # steps per checkpointed stretch of the recurrence
+
+# where the blocks lie in the program's parameter tree, in visit order
+BLOCK_KEYS = ("blocks",)
 
 PRUNABLE: Dict[str, Tuple[str, int]] = {
     "in_z": ("mix_in", 1), "in_x": ("mix_in", 1), "in_B": ("mix_in", 1),
@@ -145,13 +156,46 @@ def embed(params, tokens):
     return params["embed"]["tok"][tokens]
 
 
-def layer(params, i: int):
-    return jax.tree.map(lambda a: a[i], params["blocks"])
-
-
 def forward(params, tokens, c: Dict[str, Any]):
     h = embed(params, tokens)
     for i in range(c["n_layer"]):
-        h = block(layer(params, i), h, c)
+        h = block(jax.tree.map(lambda a: a[i], params["blocks"]), h, c)
     h = rms_norm(h, params["final_norm"]["w"])
     return jnp.matmul(h, params["head"]["w"][:, : c["vocab_size"]], precision=HI)
+
+
+# -- work counts, per block (every block alike) --------------------------
+def _count_sizes(c: Dict[str, Any]):
+    s = c["ssm_cfg"]
+    d = c["d_model"]
+    di = s["expand"] * d
+    return d, di, s["d_state"], s["headdim"], di // s["headdim"], s["d_conv"]
+
+
+def num_blocks(c: Dict[str, Any]) -> int:
+    return c["n_layer"]
+
+
+def hidden_size(c: Dict[str, Any]) -> int:
+    return c["d_model"]
+
+
+def block_matmul_params(c: Dict[str, Any], i: int) -> int:
+    """Weights of block ``i``'s matmuls (N_block)."""
+    d, di, N, P, H, K = _count_sizes(c)
+    return d * (2 * di + 2 * N + H) + di * d
+
+
+def block_params(c: Dict[str, Any], i: int) -> int:
+    """Every weight of block ``i``, norms and biases included."""
+    d, di, N, P, H, K = _count_sizes(c)
+    return block_matmul_params(c, i) + (K + 1) * (di + 2 * N) + 3 * H + di + d
+
+
+def block_fwd_flops_per_token(c: Dict[str, Any], i: int, seq: int) -> float:
+    d, di, N, P, H, K = _count_sizes(c)
+    return 2.0 * block_matmul_params(c, i) + 2.0 * K * (di + 2 * N) + 4.0 * H * N * P
+
+
+def kv_bytes_per_position(c: Dict[str, Any], i: int) -> int:
+    raise ValueError("a Mamba2 block keeps a state, not K/V per position")

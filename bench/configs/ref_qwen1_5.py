@@ -11,9 +11,15 @@ untied head. Every matmul runs at ``Precision.HIGHEST`` so that on a TPU
 it is float32 and not one bfloat16 pass.
 
 ``init`` makes seeded weights in the parameter layout that
-``repro.models.transformer`` reads (leaves stacked over layers, the
-vocabulary padded to a multiple of 128 rows); the benchmark gives the
-same weights to the program and to this reference.
+``repro.models.transformer`` reads (leaves stacked over layers under
+``blocks``, the vocabulary padded to a multiple of 128 rows); the
+benchmark gives the same weights to the program and to this reference.
+
+The module also carries the block's work counts, which
+``bench/harness/flops.py`` composes: a block's forward per token is 2 x
+its matmul weights, plus attention's QK^T and PV over the causal
+prefix, on average (S + 1) / 2 keys per query: 2 * 2 * (S + 1) / 2 * H *
+hd. Norms, biases, activations and softmax are not counted.
 """
 from __future__ import annotations
 
@@ -24,6 +30,10 @@ import jax
 import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
+F32 = 4  # bytes
+
+# where the blocks lie in the program's parameter tree, in visit order
+BLOCK_KEYS = ("blocks",)
 
 # prunable leaf (path below a block) -> (the activation it reads, how many
 # leading axes of the leaf are its input axes); Wanda scores these
@@ -124,14 +134,43 @@ def embed(params, tokens):
     return params["embed"]["tok"][tokens]
 
 
-def layer(params, i: int):
-    return jax.tree.map(lambda a: a[i], params["blocks"])
-
-
 def forward(params, tokens, c: Dict[str, Any]):
     """tokens (B, S) -> logits (B, S, vocab_size) over the real vocabulary."""
     h = embed(params, tokens)
     for i in range(c["num_hidden_layers"]):
-        h = block(layer(params, i), h, c)
+        h = block(jax.tree.map(lambda a: a[i], params["blocks"]), h, c)
     h = rms_norm(h, params["final_norm"]["w"], c["rms_norm_eps"])
     return jnp.matmul(h, params["head"]["w"][:, : c["vocab_size"]], precision=HI)
+
+
+# -- work counts, per block (every block alike) --------------------------
+def num_blocks(c: Dict[str, Any]) -> int:
+    return c["num_hidden_layers"]
+
+
+def hidden_size(c: Dict[str, Any]) -> int:
+    return c["hidden_size"]
+
+
+def block_matmul_params(c: Dict[str, Any], i: int) -> int:
+    """Weights of block ``i``'s matmuls (N_block)."""
+    s = sizes(c)
+    d, H, Hkv, hd, ff = s["d"], s["H"], s["Hkv"], s["hd"], s["ff"]
+    return d * hd * (H + 2 * Hkv) + H * hd * d + 3 * d * ff
+
+
+def block_params(c: Dict[str, Any], i: int) -> int:
+    """Every weight of block ``i``, norms and biases included."""
+    s = sizes(c)
+    return block_matmul_params(c, i) + s["hd"] * (s["H"] + 2 * s["Hkv"]) + 2 * s["d"]
+
+
+def block_fwd_flops_per_token(c: Dict[str, Any], i: int, seq: int) -> float:
+    s = sizes(c)
+    return 2.0 * block_matmul_params(c, i) + 2.0 * (seq + 1) * s["H"] * s["hd"]
+
+
+def kv_bytes_per_position(c: Dict[str, Any], i: int) -> int:
+    """K and V of one cached position in block ``i``, float32."""
+    s = sizes(c)
+    return F32 * 2 * s["Hkv"] * s["hd"]

@@ -56,7 +56,7 @@ class Driver:
         self.params_p = jax.tree.map(lambda a: a.astype(dt), self.params)
         self.pruned_p = jax.tree.map(lambda a: a.astype(dt), self.pruned)
         tuned, reports = self._finetune(self.calib[0])
-        self.first = walkcheck.program_summary(tuned, self.pruned_p, self.masks, reports)
+        self.first = walkcheck.program_summary(ref, tuned, self.pruned_p, self.masks, reports)
         del tuned
 
     def window(self, seconds: float):
@@ -70,21 +70,21 @@ class Driver:
             self.last, reports = self._finetune(self.calib[k])
             calls += 1
             blocks += len(reports)
-            epochs += [r.epochs_run for r in reports]
+            epochs += [(r.index, r.epochs_run) for r in reports]
             failed += any(not (math.isfinite(r.loss_before) and math.isfinite(r.loss_after))
                           for r in reports)
         dt = time.perf_counter() - t0
         cal = self.tr["calibration"]
         return {"attempted": calls, "failed": failed,
                 "metrics": {"walk_block_s": dt / blocks},
-                "counts": {"calls": calls, "blocks": blocks, "epochs_run": epochs,
+                "counts": {"calls": calls, "blocks_tuned": blocks, "epochs_run": epochs,
                            "seq_len": cal["seq_len"],
                            "tokens": cal["samples"] * cal["seq_len"]}}
 
     def free(self):
         last = getattr(self, "last", None)
         self.nonzero_last = 0 if last is None else int(
-            walkcheck.masked_nonzero(last["blocks"], self.masks["blocks"]))
+            walkcheck.masked_nonzero(last, self.masks))
         self.last = self.params_p = self.pruned_p = None
 
     def check(self):
@@ -92,5 +92,5 @@ class Driver:
         ref = walkcheck.reference(ctx.ref, ctx.conf, self.params, self.pruned, self.masks,
                                   self.calib[0], self.ecfg)
         self.notes = {"dropped": walkcheck.dropped(ref),
-                      "blocks": walkcheck.by_block(self.first, ref)}
+                      "per_block": walkcheck.by_block(self.first, ref)}
         return walkcheck.numbers(self.first, ref, self.nonzero_last)

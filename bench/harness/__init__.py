@@ -6,7 +6,9 @@ per-layer metric lives in a file of its own under ``bench/`` and is found
 by the name ``BENCHMARK.json`` gives it (``spec.py``). This package holds
 what all cells share: the command line (``cli.py``), the chip look and
 the peaks table (``device.py``), the traffic generator (``traffic.py``),
-the operation and byte counts (``flops.py``), the trace reduction
+where a model's blocks lie in its parameter tree (``layout.py``), the
+conventions that compose the references' per-block operation and byte
+counts (``flops.py``), the trace reduction
 (``xplane.py``), the compile-event capture (``monitor.py``), the
 benchmark's own Wanda masks (``wanda.py``) and the comparisons with the
 plain references that decide ``correct`` (``walkcheck.py``,

@@ -206,6 +206,7 @@ class RunView:
         from harness import device, flops
 
         self.conf = ctx.conf
+        self.ref = ctx.ref
         self.traffic = ctx.traffic
         self.counts = win["counts"]
         self.trace = red

@@ -46,15 +46,15 @@ def walk_half_batch(ctx):
 def walk_answer(ctx):
     """The tuned model comes back from ``finetune`` with its block weights
     scaled by 1.1."""
-    import jax
     from repro.core import ebft
+
+    from harness import layout
 
     finetune = ebft.finetune
 
     def altered(*args, **kw):
         tuned, reports = finetune(*args, **kw)
-        blocks = jax.tree.map(lambda a: a * 1.1, tuned["blocks"])
-        return {**tuned, "blocks": blocks}, reports
+        return layout.map_blocks(ctx.ref, lambda a: a * 1.1, tuned), reports
 
     return _patched(ebft, "finetune", altered)
 

@@ -11,6 +11,30 @@
 
 A new configuration, traffic mix, cell or per-layer metric is a new file
 and a new entry in BENCHMARK.json; no existing file needs an edit.
+
+The harness names no block key and no model family: what it needs of a
+model comes from the reference module that the configuration names
+(``"reference"``), which defines
+
+    BLOCK_KEYS          the top-level keys of the program's parameter tree
+                        that hold the blocks, in visit order, each a stack
+                        of blocks on the leaves' leading axis (layout.py)
+    PRUNABLE            leaf path below a block -> (tap, input axes) or
+                        (tap, input axes, "experts"): the linear layers
+                        Wanda prunes, scored on the block's tap of that
+                        name; "experts": the leaf's leading axis and the
+                        tap's index experts (wanda.py)
+    init(key, conf)     seeded float32 weights in the program's layout
+    embed(params, tokens)
+    block(bp, h, conf, taps=False, precision=HIGHEST)
+                        one block on h (B, S, d); with ``taps`` also its
+                        taps, (B, S, inputs) or (experts, B, S, inputs)
+    forward(params, tokens, conf)
+                        logits over the real vocabulary
+    num_blocks(conf), hidden_size(conf)
+    block_matmul_params(conf, i), block_params(conf, i),
+    block_fwd_flops_per_token(conf, i, seq), kv_bytes_per_position(conf, i)
+                        block i's work counts (flops.py composes them)
 """
 from __future__ import annotations
 

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from harness import layout
+
 GRAD_FLOOR = 1e-3  # leaves under this share of the median leaf's first gradient
 
 
@@ -65,22 +67,22 @@ def change_norms(tuned_block, start_block):
 
 
 @jax.jit
-def masked_nonzero(tuned_blocks, mask_blocks):
-    return sum(jnp.sum((m == 0) & (t != 0)) for t, m in
-               zip(jax.tree.leaves(tuned_blocks), jax.tree.leaves(mask_blocks)))
+def masked_nonzero(tuned, masks):
+    """Weights not zero where their mask is, over the whole tree (the
+    masks outside the blocks are scalar ones)."""
+    return sum(jax.tree.leaves(jax.tree.map(lambda t, m: jnp.sum((m == 0) & (t != 0)),
+                                            tuned, masks)))
 
 
-def program_summary(tuned, pruned, masks, reports) -> Dict[str, Any]:
+def program_summary(ref, tuned, pruned, masks, reports) -> Dict[str, Any]:
     """What the check keeps of the program's first call (host values)."""
-    blocks = []
+    per_block = []
     for i, r in enumerate(reports):
-        ti = jax.tree.map(lambda a: a[i], tuned["blocks"])
-        si = jax.tree.map(lambda a: a[i], pruned["blocks"])
-        blocks.append({"loss_before": r.loss_before, "loss_after": r.loss_after,
-                       "history": list(r.history[1:]), "epochs": r.epochs_run,
-                       "change": named(change_norms(ti, si))})
-    return {"blocks": blocks,
-            "masked_nonzero": int(masked_nonzero(tuned["blocks"], masks["blocks"]))}
+        ti, si = layout.block(ref, tuned, i), layout.block(ref, pruned, i)
+        per_block.append({"loss_before": r.loss_before, "loss_after": r.loss_after,
+                          "history": list(r.history[1:]), "epochs": r.epochs_run,
+                          "change": named(change_norms(ti, si))})
+    return {"per_block": per_block, "masked_nonzero": int(masked_nonzero(tuned, masks))}
 
 
 def reference(ref, conf, dense, pruned, masks, tokens, ecfg: Dict[str, Any]):
@@ -111,9 +113,9 @@ def reference(ref, conf, dense, pruned, masks, tokens, ecfg: Dict[str, Any]):
     h = jax.jit(ref.embed)(dense, jnp.asarray(tokens))
     student = [h[j:j + mb] for j in range(0, h.shape[0], mb)]
     teacher = student
-    blocks = []
-    for i in range(jax.tree.leaves(masks["blocks"])[0].shape[0]):
-        d, s, m = ref.layer(dense, i), ref.layer(pruned, i), ref.layer(masks, i)
+    per_block = []
+    for i in range(layout.count(ref, masks)):
+        d, s, m = (layout.block(ref, t, i) for t in (dense, pruned, masks))
         teacher = [fwd(d, x) for x in teacher]
         bw = s
         before = mean_loss(bw, m, student, teacher)
@@ -131,12 +133,12 @@ def reference(ref, conf, dense, pruned, masks, tokens, ecfg: Dict[str, Any]):
             if plateau(history, ecfg["patience"], ecfg["rel_tol"]):
                 break
         tuned = jax.tree.map(jnp.multiply, bw, m)
-        blocks.append({"loss_before": before, "loss_after": mean_loss(bw, m, student, teacher),
+        per_block.append({"loss_before": before, "loss_after": mean_loss(bw, m, student, teacher),
                        "history": history[1:], "epochs": len(history) - 1,
                        "change": named(change_norms(tuned, s)), "first_grad": first_grad})
         student = [fwd(tuned, x) for x in student]
         del bw, opt, tuned
-    return {"blocks": blocks}
+    return {"per_block": per_block}
 
 
 def rel(a: float, b: float) -> float:
@@ -154,7 +156,7 @@ def dropped(ref: Dict[str, Any]) -> Dict[str, float]:
     """Per block, the leaves the change leaves out, with their first
     reference gradient over the block's median leaf's."""
     out = {}
-    for i, b in enumerate(ref["blocks"]):
+    for i, b in enumerate(ref["per_block"]):
         g = b["first_grad"]
         med = float(np.median(list(g.values())))
         keep = set(moved(g))
@@ -166,7 +168,7 @@ def by_block(prog: Dict[str, Any], ref: Dict[str, Any]) -> List[Dict[str, Any]]:
     """Per block: each epoch's loss gap, the worst leaf's change gap, the
     gaps of the losses before and after tuning, the epochs' difference."""
     out = []
-    for p, r in zip(prog["blocks"], ref["blocks"]):
+    for p, r in zip(prog["per_block"], ref["per_block"]):
         keep = moved(r["first_grad"])
         med_c = float(np.median([r["change"][k] for k in keep]))
         common = min(len(p["history"]), len(r["history"]))
@@ -184,7 +186,7 @@ def numbers(prog: Dict[str, Any], ref: Dict[str, Any], nonzero_last: int) -> Dic
     """Each number is the worst over the blocks; a NaN reads as NaN, and a
     walk that reports other blocks than the reference's reads inf."""
     blocks = by_block(prog, ref)
-    short = [np.inf] if len(prog["blocks"]) != len(ref["blocks"]) else []
+    short = [np.inf] if len(prog["per_block"]) != len(ref["per_block"]) else []
     return {
         "loss_before": blocks[0]["before"],
         "history": float(np.max([g for b in blocks for g in b["history"]] + short)),

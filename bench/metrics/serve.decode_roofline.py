@@ -15,6 +15,6 @@ def read(run):
     s = run.trace.device_seconds(PROGRAMS)
     if s <= 0:
         return None
-    need = sum(run.flops.decode_request_bytes(run.conf, p, n)
+    need = sum(run.flops.decode_request_bytes(run.ref, run.conf, p, n)
                for p, n in run.counts["requests"])
     return 100.0 * need / run.peaks["hbm_bytes_per_s"] / s

@@ -5,6 +5,6 @@ bf16 peak."""
 
 
 def read(run):
-    work = sum(run.flops.serve_request_flops(run.conf, p, n)
+    work = sum(run.flops.serve_request_flops(run.ref, run.conf, p, n)
                for p, n in run.counts["requests"])
     return 100.0 * work / (run.trace.window_s * run.chips * run.peaks["flops"])

@@ -10,4 +10,4 @@ def read(run):
     sp = spans.of(run)
     if not sp or not sp.named("ebft/walk"):
         return None
-    return sp.builds_in(("ebft/walk",)) / run.counts["blocks"]
+    return sp.builds_in(("ebft/walk",)) / run.counts["blocks_tuned"]

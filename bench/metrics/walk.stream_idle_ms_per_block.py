@@ -11,4 +11,4 @@ def read(run):
     sp = spans.of(run)
     if not sp or not sp.named(*PHASES):
         return None
-    return 1e3 * sp.idle_s(PHASES) / run.counts["blocks"]
+    return 1e3 * sp.idle_s(PHASES) / run.counts["blocks_tuned"]

@@ -9,4 +9,4 @@ def read(run):
     sp = spans.of(run)
     if not sp or not sp.named("walk/tune"):
         return None
-    return 1e3 * sp.idle_s(("walk/tune",)) / run.counts["blocks"]
+    return 1e3 * sp.idle_s(("walk/tune",)) / run.counts["blocks_tuned"]

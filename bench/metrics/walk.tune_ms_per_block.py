@@ -6,4 +6,4 @@ PROGRAMS = ("fused_run",)
 
 def read(run):
     s = run.trace.device_seconds(PROGRAMS)
-    return 1e3 * s / run.counts["blocks"] if s > 0 else None
+    return 1e3 * s / run.counts["blocks_tuned"] if s > 0 else None

@@ -45,6 +45,31 @@ TINY_MAMBA = {
     "program_fields": {"family": "ssm", "dtype": "float32"},
 }
 
+# the program's tiny_moe (DeepSeek-MoE's layout at test widths: one dense
+# block, then two MoE blocks of 8 routed experts, top-2, 2 shared); its
+# reference (ref_moe.py, beside this file) is dropless, and a capacity
+# factor of n_routed_experts / num_experts_per_tok makes each expert's
+# capacity the whole token group, so the program drops no token either
+TINY_MOE = {
+    "name": "tiny_moe", "source": "test", "hidden_size": 64, "intermediate_size": 256,
+    "moe_intermediate_size": 32, "num_attention_heads": 4, "num_key_value_heads": 4,
+    "num_hidden_layers": 3, "first_k_dense_replace": 1, "n_routed_experts": 8,
+    "num_experts_per_tok": 2, "n_shared_experts": 2, "vocab_size": 512,
+    "rms_norm_eps": 1e-06, "rope_theta": 10000.0,
+    "reference": "ref_moe.py", "registered": "tiny_moe",
+    "model_config": {"moe_capacity_factor": 4.0},
+    "agrees": {"d_model": "hidden_size", "d_ff": "intermediate_size",
+               "moe_d_ff": "moe_intermediate_size", "num_heads": "num_attention_heads",
+               "num_kv_heads": "num_key_value_heads", "num_layers": "num_hidden_layers",
+               "moe_first_dense": "first_k_dense_replace",
+               "moe_num_experts": "n_routed_experts", "moe_top_k": "num_experts_per_tok",
+               "moe_num_shared": "n_shared_experts", "vocab_size": "vocab_size",
+               "rope_theta": "rope_theta"},
+    "program_fields": {"family": "moe", "qkv_bias": False, "mlp_act": "swiglu",
+                       "norm": "rmsnorm", "tie_embeddings": False, "dtype": "float32",
+                       "moe_capacity_factor": 4.0, "moe_dispatch_groups": 1},
+}
+
 TINY_WALK = {
     "driver": "walk", "corpus": CORPUS,
     "calibration": {"samples": 16, "seq_len": 32},
@@ -75,15 +100,31 @@ CELLS = {
     "tiny-walk": ("tiny_qwen", TINY_QWEN, "tiny_walk", TINY_WALK, WALK_LIMITS),
     "tiny-mamba-walk": ("tiny_mamba", TINY_MAMBA, "tiny_walk", TINY_WALK, WALK_LIMITS),
     "tiny-serve": ("tiny_qwen", TINY_QWEN, "tiny_serve", TINY_SERVE, SERVE_LIMITS),
+    "tiny-moe-walk": ("tiny_moe", TINY_MOE, "tiny_walk", TINY_WALK, WALK_LIMITS),
+    "tiny-moe-serve": ("tiny_moe", TINY_MOE, "tiny_serve", TINY_SERVE, SERVE_LIMITS),
 }
+# references kept with the tests, which make_root adds as new files
+TEST_REFERENCES = ("ref_moe.py",)
+
+
+def reference(conf):
+    """The plain reference module a configuration names."""
+    from harness import spec as S
+
+    name = conf["reference"]
+    where = "tests" if name in TEST_REFERENCES else "configs"
+    return S.load_module(os.path.join(BENCH, where, name), f"bench_tiny_{conf['name']}")
 
 
 def make_root(tmp: str) -> str:
     """A checkout-like root under ``tmp``: BENCHMARK.json and bench/ copied,
-    the tiny configurations, traffic, limits and cells added."""
+    the tiny configurations, their test references, traffic, limits and
+    cells added; no file that bench/ has is changed."""
     root = os.path.join(tmp, "root")
     shutil.copytree(BENCH, os.path.join(root, "bench"),
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    for name in TEST_REFERENCES:
+        shutil.copy(os.path.join(BENCH, "tests", name), os.path.join(root, "bench", "configs"))
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
     for cell, (cname, conf, tname, traffic, limits) in CELLS.items():

@@ -7,22 +7,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from harness import program, spec as S
+from harness import layout, program
 
 RTOL = 1e-4  # of the output's scale: float32 against float32, summation order only
 
 
-@pytest.mark.parametrize("conf", [T.TINY_QWEN, T.TINY_MAMBA], ids=["qwen1_5", "mamba2"])
+@pytest.mark.parametrize("conf", [T.TINY_QWEN, T.TINY_MAMBA, T.TINY_MOE],
+                         ids=["qwen1_5", "mamba2", "moe"])
 def test_reference_matches_model(conf):
-    ref = S.load_module(f"{T.BENCH}/configs/{conf['reference']}", f"ref_{conf['name']}")
+    ref = T.reference(conf)
     model = program.build(program.model_config(conf))
     params = jax.jit(lambda k: ref.init(k, conf))(jax.random.PRNGKey(3))
     toks = jnp.asarray(np.random.default_rng(0).integers(0, conf["vocab_size"], (2, 32)),
                        jnp.int32)
     h = ref.embed(params, toks)
     pos = jnp.arange(32)[None, :]
-    for i in range(2):
-        bp = ref.layer(params, i)
+    for i in range(layout.count(ref, params)):
+        bp = layout.block(ref, params, i)
         want = ref.block(bp, h, conf)
         got = model.apply_block(None, i, bp, h, pos)
         assert float(jnp.max(jnp.abs(got - want))) <= RTOL * float(jnp.max(jnp.abs(want)))

@@ -141,7 +141,7 @@ def test_builds_are_counted_per_span(sp):
 
 
 def test_readers_on_the_recorded_trace(sp):
-    got = {m: reader(m).read(run_view(sp, blocks=1)) for m in METRICS}
+    got = {m: reader(m).read(run_view(sp, blocks_tuned=1)) for m in METRICS}
     assert got["walk.tune_idle_ms_per_block"] == pytest.approx(sp.idle_ns(one(sp, "walk/tune")) / MS)
     stream = sum(sp.idle_ns(one(sp, n)) for n in ("walk/setup", "walk/student")) / MS
     assert got["walk.stream_idle_ms_per_block"] == pytest.approx(stream)
@@ -158,8 +158,8 @@ def test_readers_report_nothing_without_program_spans():
     red = spans.reduce(os.path.join(DATA, "fixture.xplane.pb"))
     assert red.spans == []
     for m in METRICS:
-        assert reader(m).read(run_view(red, blocks=4)) is None
-        assert reader(m).read(run_view(None, blocks=4)) is None
+        assert reader(m).read(run_view(red, blocks_tuned=4)) is None
+        assert reader(m).read(run_view(None, blocks_tuned=4)) is None
 
 
 def test_of_finds_the_traced_run_in_the_calling_frame(tmp_path):
@@ -171,7 +171,7 @@ def test_of_finds_the_traced_run_in_the_calling_frame(tmp_path):
                                       "host.xplane.pb"))
     evs, wall0 = events()
     mon = types.SimpleNamespace(events=evs)
-    view = types.SimpleNamespace(counts={"blocks": 1})
+    view = types.SimpleNamespace(counts={"blocks_tuned": 1})
     got = spans.of(view)
     assert [s.name for s in got.spans][:2] == ["ebft/walk", "walk/setup"]
     assert got.builds_in(("ebft/walk",)) == 1

@@ -63,6 +63,25 @@ def test_new_files_resolve_by_name(tmp_path):
     assert {k: v for k, v in after.items() if k in before} == before
 
 
+def test_tiny_cells_are_new_files_only(tmp_path):
+    """The tiny cells, tiny_moe's with a reference of its own, go into the
+    test root as new files and BENCHMARK.json entries: every file of the
+    benchmark reads as committed."""
+    root = T.make_root(str(tmp_path))
+    committed = {k: v for k, v in digests(T.BENCH).items()
+                 if not k.startswith("tests" + os.sep) and "__pycache__" not in k}
+    copied = digests(os.path.join(root, "bench"))
+    assert {k: copied.get(k) for k in committed} == committed
+    spec = S.load_spec(root)
+    for name in ("tiny-moe-walk", "tiny-moe-serve"):
+        ref = S.cell(spec, name, root).reference()
+        assert ref.__file__ == os.path.join(root, "bench", "configs", "ref_moe.py")
+        assert ref.BLOCK_KEYS == ("dense_blocks", "moe_blocks")
+    committed_spec = S.load_spec(T.ROOT)
+    assert committed_spec["configs"] == spec["configs"][:len(committed_spec["configs"])]
+    assert committed_spec["workloads"] == spec["workloads"][:len(committed_spec["workloads"])]
+
+
 def test_committed_spec_resolves():
     spec = S.load_spec(T.ROOT)
     assert spec["command"] == ["python3", "bench/run.py"] and spec["paths"] == ["bench"]
